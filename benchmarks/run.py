@@ -22,6 +22,7 @@ import sys
 import time
 import traceback
 
+import repro.core as core
 from benchmarks import (
     bench_surrogate_accuracy,
     bench_dispatch_gbe,
@@ -121,6 +122,7 @@ def write_results(entries, path=None, commit=None) -> str:
 
 
 def main() -> None:
+    core.use_compile_cache()
     prefixes = [a for a in sys.argv[1:] if not a.startswith("-")]
     print("name,us_per_call,derived")
     failures = 0

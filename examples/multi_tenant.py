@@ -19,6 +19,7 @@ import repro.core as core
 
 
 def main():
+    core.use_compile_cache()
     cluster = core.h100_cluster()
     sim = core.BandwidthSimulator(cluster)
     tables = core.IntraHostTables(cluster, sim)

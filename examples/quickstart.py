@@ -13,6 +13,7 @@ import repro.core as core
 
 
 def main():
+    core.use_compile_cache()
     # 1. the cluster + its (black-box) bandwidth landscape
     cluster = core.h100_cluster()
     sim = core.BandwidthSimulator(cluster)

@@ -5,6 +5,7 @@ Public surface:
     cluster.Cluster, cluster.PAPER_CLUSTERS, bandwidth_sim.BandwidthSimulator
   Hierarchical surrogate (Sec. 4.2):
     intra_host.IntraHostTables, surrogate.SurrogatePredictor,
+    surrogate.audit_descent / SCORE_RTOL (fused-scan agreement contract),
     training.train_surrogate / online_finetune / evaluate_surrogate
   Hybrid search (Sec. 4.3):
     search.eha_search / pts_search / hybrid_search
@@ -48,9 +49,13 @@ Public surface:
     forensics.DossierRecorder / capture / DecisionDossier,
     reconstruct / replay_decision / whatif, RegretLedger / absorb_regret,
     bandwidth_decomposition (see docs/observability.md §Forensics)
+  Entry-point set-up:
+    compile_cache.use_compile_cache (persistent XLA compile cache; called by
+    scripts and examples, never at import)
 """
 
 from repro.core.bandwidth_sim import BW_SCALE, BandwidthSimulator
+from repro.core.compile_cache import use_compile_cache
 from repro.core.contended_dataset import (
     ContendedSample,
     TelemetryHarvester,
@@ -173,8 +178,11 @@ from repro.core.search import (
     pts_search,
 )
 from repro.core.surrogate import (
+    SCORE_RTOL,
     ContendedSurrogatePredictor,
+    DescentAudit,
     SurrogatePredictor,
+    audit_descent,
     init_contended_params,
 )
 from repro.core.training import (
@@ -199,6 +207,7 @@ __all__ = [
     "het_ra_cluster",
     "het_va_cluster",
     "tpu_pod_cluster",
+    "use_compile_cache",
     "BandPilotDispatcher",
     "BaselineDispatcher",
     "DispatcherService",
@@ -264,6 +273,9 @@ __all__ = [
     "pts_search",
     "SurrogatePredictor",
     "ContendedSurrogatePredictor",
+    "SCORE_RTOL",
+    "DescentAudit",
+    "audit_descent",
     "init_contended_params",
     "AdmissionTracer",
     "DecisionRecord",

@@ -719,6 +719,7 @@ def absorb_predictor_stats(reg: MetricsRegistry, stats, **labels) -> None:
         ("n_model_calls", "candidates sent through a surrogate apply"),
         ("n_capped", "candidates degraded by a contention branch"),
         ("n_scan_steps", "fused on-device elimination rounds"),
+        ("n_scan_declines", "inconsistent fused descents redone on the host"),
         ("cache_hits", "prediction-cache hits"),
         ("cache_misses", "prediction-cache misses"),
     ):

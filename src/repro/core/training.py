@@ -56,6 +56,31 @@ def _mse_loss(apply_fn, params, x, mask, y):
     return jnp.mean(jnp.square(pred - y))
 
 
+def train_step(apply_fn, config: TrainConfig):
+    """-> (opt_init, step): the jitted AdamW step of every trainee.
+
+    ``step(params, opt_state, x, mask, targets, idx)`` gathers the minibatch
+    ``idx`` from the device-resident dataset and returns
+    ``(params, opt_state, loss)``."""
+    opt_cfg = AdamWConfig(
+        lr=config.lr, weight_decay=config.weight_decay, grad_clip_norm=1.0
+    )
+    opt_init, opt_update = adamw(
+        opt_cfg, cosine_schedule(config.steps, config.warmup_steps)
+    )
+
+    @jax.jit
+    def step(params, opt_state, x, mask, targets, idx):
+        xb, mb, yb = x[idx], mask[idx], targets[idx]
+        loss, grads = jax.value_and_grad(
+            lambda p: _mse_loss(apply_fn, p, xb, mb, yb)
+        )(params)
+        params, opt_state, metrics = opt_update(grads, opt_state, params)
+        return params, opt_state, loss
+
+    return opt_init, step
+
+
 def _fit(
     apply_fn,
     params: PyTree,
@@ -66,31 +91,19 @@ def _fit(
 ) -> Tuple[PyTree, Dict[str, float]]:
     """The shared AdamW loop: minibatch MSE on normalized log-bandwidth."""
     n = int(x.shape[0])
-    opt_cfg = AdamWConfig(
-        lr=config.lr, weight_decay=config.weight_decay, grad_clip_norm=1.0
-    )
-    opt_init, opt_update = adamw(
-        opt_cfg, cosine_schedule(config.steps, config.warmup_steps)
-    )
+    opt_init, step = train_step(apply_fn, config)
     opt_state = opt_init(params)
-
-    @jax.jit
-    def step(params, opt_state, idx):
-        xb, mb, yb = x[idx], mask[idx], targets[idx]
-        loss, grads = jax.value_and_grad(
-            lambda p: _mse_loss(apply_fn, p, xb, mb, yb)
-        )(params)
-        params, opt_state, metrics = opt_update(grads, opt_state, params)
-        return params, opt_state, loss
 
     rng = np.random.default_rng(config.seed)
     t0 = time.time()
     loss = np.inf
     for i in range(config.steps):
         idx = jnp.asarray(rng.integers(0, n, size=min(config.batch_size, n)))
-        params, opt_state, loss = step(params, opt_state, idx)
+        params, opt_state, loss = step(params, opt_state, x, mask, targets,
+                                       idx)
         if config.log_every and (i + 1) % config.log_every == 0:
             print(f"  surrogate step {i + 1}/{config.steps} loss={float(loss):.5f}")
+    jax.block_until_ready(params)  # steps are enqueued, not yet done
     info = {
         "train_seconds": time.time() - t0,
         "final_loss": float(loss),

@@ -2,16 +2,18 @@
 
 The load-bearing guarantees:
 
-* every round of a fused ``lax.scan`` descent scores children at exactly
-  ``np.float32(host-path float64 score)`` and eliminates the same slot the
-  host loop would (audited round by round, bare-isolated AND through the
-  analytic contention cap table);
+* every round of a fused ``lax.scan`` descent scores children within
+  ``SCORE_RTOL`` of the host path and eliminates the slot the host
+  loop would, except at a near-tie (``surrogate.audit_descent``, audited
+  round by round, bare-isolated AND through the analytic contention cap
+  table);
 * pinned scheduler-trace replays select **byte-identical subsets** with the
   scan enabled (the new default) vs disabled (``use_scan=False``), in both
   analytic and learned contention modes;
 * the cross-search inference batcher is value-neutral: whichever requests
-  happen to fuse into one padded apply, every caller receives bit-identical
-  outputs to a solo apply (property-based, concurrent threads included);
+  happen to fuse into one padded apply, every caller receives outputs
+  within ``SCORE_RTOL`` of a solo apply (property-based, concurrent threads
+  included);
 * the LRU-capped lifetime memo can only forget values, never change them;
 * ``PredictorStats`` accounts the scan path in its own bucket — no
   double-counting through the ``collect_stats`` chain merge.
@@ -74,23 +76,23 @@ def _multi_host_parent(cl, rng, n0, exclude=()):
 
 
 def _audit_descent(cl, predictor, res, parent, k):
-    """Replay the host elimination loop round by round against a
-    ScanResult: f32 score identity at every live slot, same elimination."""
-    parent = sorted(parent)
-    s = list(parent)
-    assert res.n_rounds == len(parent) - k
-    for r in range(res.n_rounds):
-        live = np.nonzero(res.sels[r])[0]
-        assert [parent[i] for i in live] == s
-        children = [s[:i] + s[i + 1:] for i in range(len(s))]
-        host = predictor.predict(children)          # float64 host path
-        host32 = np.float32(host)
-        np.testing.assert_array_equal(res.scores[r][live], host32)
-        # same argmax over the f32 scores (first-wins tie break both sides)
-        j = int(np.argmax(host32))
-        assert res.elims[r] == live[j]
-        s.pop(j)
-    assert res.subset == s and len(s) == k
+    """Hold a ScanResult to the agreement contract against the host loop:
+    per-child scores within SCORE_RTOL, host-argmax eliminations
+    except at near-ties, survivors of the replayed descent."""
+    audit = surr.audit_descent(predictor, res, parent, k)
+    assert not audit.violations, audit
+    assert res.subset == sorted(res.subset) and len(res.subset) == k
+
+
+def _same_or_near_tie(a_subset, a_bw, b_subset, b_bw):
+    """Two searches over the same state agree: the same placement with
+    scores within SCORE_RTOL, or different placements only where
+    their scores are a near-tie (within twice that)."""
+    rtol = surr.SCORE_RTOL
+    if a_subset == b_subset:
+        np.testing.assert_allclose(a_bw, b_bw, rtol=rtol)
+    else:
+        np.testing.assert_allclose(a_bw, b_bw, rtol=2 * rtol)
 
 
 # ---------------------------------------------------------------------------
@@ -181,8 +183,8 @@ def test_hybrid_search_scan_vs_host_contended(stack):
                                        use_scan=use_scan)
         wrapped = core.cached_contention_predictor(cl, pred, led)
         results[use_scan] = core.hybrid_search(cl, tables, wrapped, avail, 9)
-    assert results[True].subset == results[False].subset
-    assert results[True].predicted_bw == results[False].predicted_bw
+    _same_or_near_tie(results[True].subset, results[True].predicted_bw,
+                      results[False].subset, results[False].predicted_bw)
 
 
 def _scan_dispatcher(cl, tables, params, use_scan, **kw):
@@ -347,8 +349,10 @@ def _check_batcher_neutral(seed: int) -> None:
     if errs:
         raise errs[0]
     assert batcher.n_requests == n_workers
+    # a fused request runs at a larger padded batch, which XLA-CPU's matmul
+    # already rounds differently (up to ~3e-6 relative): the score contract
     for w, g in zip(want, got):
-        np.testing.assert_array_equal(w, g)
+        np.testing.assert_allclose(g, w, rtol=surr.SCORE_RTOL)
 
 
 @settings(max_examples=8, deadline=None)
@@ -381,7 +385,8 @@ def test_batcher_through_predictor(stack):
 
 def test_joint_search_batched_identical(stack):
     """joint_hybrid_search with the batcher (threaded orders) picks the
-    same plan as the sequential path."""
+    same plan as the sequential path: the batcher's padded batches are other
+    XLA shapes, so the scores agree to the contract, not to the bit."""
     cl, sim, tables, params = stack
     pred = core.SurrogatePredictor(cl, tables, params)
     led = JobLedger(cl)
@@ -390,10 +395,12 @@ def test_joint_search_batched_identical(stack):
     seq = search.joint_hybrid_search(cl, tables, pred, led, reqs)
     bat = search.joint_hybrid_search(cl, tables, pred, led, reqs,
                                      batcher=InferenceBatcher())
-    assert seq.order == bat.order
-    assert [p.subset for p in seq.placements] == \
-        [p.subset for p in bat.placements]
-    assert seq.total_predicted_bw == bat.total_predicted_bw
+    _same_or_near_tie(
+        (seq.order, [p.subset for p in seq.placements]),
+        seq.total_predicted_bw,
+        (bat.order, [p.subset for p in bat.placements]),
+        bat.total_predicted_bw,
+    )
 
 
 def test_defrag_proposer_batcher_neutral(stack):

@@ -14,12 +14,7 @@ from repro.parallel import sharding as shd
 
 def _mesh(shape, axes):
     # resolve_spec only needs mesh.shape; an abstract mesh is enough.
-    # jax >= 0.5 takes (axis_sizes, axis_names); 0.4.x takes one tuple of
-    # (name, size) pairs.
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 M = _mesh((2, 4, 4), ("pod", "data", "model"))
