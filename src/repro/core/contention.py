@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import features as feat_lib
+from repro.core import telemetry
 from repro.core.bandwidth_sim import (
     INTER_EFF,
     _jitter,
@@ -391,18 +392,25 @@ class ContentionAwarePredictor:
             return base_elim(parent, k)  # exact pass-through, like _degrade
         if not self.ledger.busy().isdisjoint(parent):
             return None  # cap depends on disjointness: not table-gatherable
-        snap = self._snapshot()
-        if snap.touch.shape[0] == 0 and not health:
-            # no cross-host tenants: both modes leave candidates untouched
-            return base_elim(parent, k)
         mode = "analytic" if self.force_analytic else self.mode
-        if mode != "analytic" or not self.vectorized:
-            return None
         tables = getattr(self.base, "tables", None)
-        if tables is None:
+        caps = None
+        with telemetry.span("cap.table") as sp:
+            snap = self._snapshot()
+            # no cross-host tenants: both modes leave candidates untouched
+            uncapped = snap.touch.shape[0] == 0 and not health
+            if (not uncapped and mode == "analytic" and self.vectorized
+                    and tables is not None):
+                if sp:
+                    sp["rebuilt"] = self._cap_tab_version != (
+                        self.ledger.uid, self.ledger.version)
+                dt = feat_lib.device_tables(self.cluster, tables)
+                caps = self._cap_table(dt, snap)
+        if uncapped:
+            return base_elim(parent, k)
+        if caps is None:
             return None
-        dt = feat_lib.device_tables(self.cluster, tables)
-        res = base_elim(parent, k, caps=self._cap_table(dt, snap))
+        res = base_elim(parent, k, caps=caps)
         if res is not None:
             self.stats.n_capped += res.n_capped
         return res
@@ -449,92 +457,94 @@ class ContentionAwarePredictor:
         health = getattr(self.ledger, "health_active", False)
         if len(self.ledger) == 0 and not health:
             return iso
-        t0 = time.time()
-        out = iso.copy()
-        inner = 0.0  # time spent inside the contended model, not the wrapper
-        mode = "analytic" if self.force_analytic else self.mode
-        if mode == "learned" and self.vectorized:
-            snap = self._snapshot()
-            _, counts, disjoint = _subset_grid(
-                snap, subsets, self.cluster.n_hosts, self.cluster.n_gpus
-            )
-            part = counts > 0
-            contended = (part.sum(axis=1) > 1) & (
-                ((disjoint @ snap.touch) * part) > 0
-            ).any(axis=1)
-            learned_mask = contended
-            if health:
-                # Degraded fabric: every candidate takes the analytic cap
-                # (the snapshot's rail vector carries the degrade factors),
-                # and the learned head is consulted only for contended
-                # candidates that touch no health-perturbed host — the
-                # surrogate never saw degraded rails in training.
+        with telemetry.span("cap.degrade"):
+            t0 = time.time()
+            out = iso.copy()
+            inner = 0.0  # time inside the contended model, not the wrapper
+            mode = "analytic" if self.force_analytic else self.mode
+            if mode == "learned" and self.vectorized:
+                snap = self._snapshot()
+                _, counts, disjoint = _subset_grid(
+                    snap, subsets, self.cluster.n_hosts, self.cluster.n_gpus
+                )
+                part = counts > 0
+                contended = (part.sum(axis=1) > 1) & (
+                    ((disjoint @ snap.touch) * part) > 0
+                ).any(axis=1)
+                learned_mask = contended
+                if health:
+                    # Degraded fabric: every candidate takes the analytic cap
+                    # (the snapshot's rail vector carries the degrade factors),
+                    # and the learned head is consulted only for contended
+                    # candidates that touch no health-perturbed host — the
+                    # surrogate never saw degraded rails in training.
+                    caps = _caps_from_snapshot_batched(
+                        self.cluster, {}, subsets,
+                        jitter_cache=self._jitter_cache, snap=snap,
+                    )
+                    capped = caps < out
+                    out[capped] = caps[capped]
+                    self.stats.n_capped += int(capped.sum())
+                    learned_mask = contended & ~(
+                        part & snap.degraded[None, :]
+                    ).any(axis=1)
+                idx = np.nonzero(learned_mask)[0].tolist()
+                if idx:
+                    before = self.contended.predict_seconds
+                    learned = self.contended.predict(
+                        [subsets[i] for i in idx], self.ledger
+                    )
+                    inner = self.contended.predict_seconds - before
+                    for i, p in zip(idx, learned):
+                        if p < out[i]:
+                            out[i] = p
+                            self.stats.n_capped += 1
+                self.stats.wrapper_seconds += time.time() - t0 - inner
+                return out
+            if self.vectorized:  # analytic: batched caps over the snapshot
                 caps = _caps_from_snapshot_batched(
                     self.cluster, {}, subsets,
-                    jitter_cache=self._jitter_cache, snap=snap,
+                    jitter_cache=self._jitter_cache, snap=self._snapshot(),
                 )
                 capped = caps < out
                 out[capped] = caps[capped]
                 self.stats.n_capped += int(capped.sum())
-                learned_mask = contended & ~(
-                    part & snap.degraded[None, :]
-                ).any(axis=1)
-            idx = np.nonzero(learned_mask)[0].tolist()
-            if idx:
-                before = self.contended.predict_seconds
-                learned = self.contended.predict(
-                    [subsets[i] for i in idx], self.ledger
-                )
-                inner = self.contended.predict_seconds - before
-                for i, p in zip(idx, learned):
-                    if p < out[i]:
-                        out[i] = p
+                self.stats.wrapper_seconds += time.time() - t0
+                return out
+            # Legacy scalar paths (the throughput bench's before-side):
+            # snapshot the cross-host jobs per host once per call, not per
+            # candidate.
+            cross_by_host = self.ledger.cross_jobs_by_host()
+            degrade = self.ledger.host_degrade if health else None
+            if mode == "learned" and health:
+                mode = "analytic"  # scalar learned path has no degraded view
+            if mode == "learned":
+                idx = [
+                    i for i, s in enumerate(subsets)
+                    if self._contended_by(cross_by_host, s)
+                ]
+                if idx:
+                    # model inference is accounted by the contended predictor's
+                    # own predict_seconds; keep this counter wrapper-only
+                    before = self.contended.predict_seconds
+                    learned = self.contended.predict(
+                        [subsets[i] for i in idx], self.ledger
+                    )
+                    inner = self.contended.predict_seconds - before
+                    for i, p in zip(idx, learned):
+                        if p < out[i]:
+                            out[i] = p
+                            self.stats.n_capped += 1
+            else:
+                for i, s in enumerate(subsets):
+                    cap = _cap_from_snapshot(
+                        self.cluster, cross_by_host, s, degrade=degrade
+                    )
+                    if cap < out[i]:
+                        out[i] = cap
                         self.stats.n_capped += 1
             self.stats.wrapper_seconds += time.time() - t0 - inner
             return out
-        if self.vectorized:  # analytic, batched caps over the version snapshot
-            caps = _caps_from_snapshot_batched(
-                self.cluster, {}, subsets,
-                jitter_cache=self._jitter_cache, snap=self._snapshot(),
-            )
-            capped = caps < out
-            out[capped] = caps[capped]
-            self.stats.n_capped += int(capped.sum())
-            self.stats.wrapper_seconds += time.time() - t0
-            return out
-        # Legacy scalar paths (the throughput bench's before-side): snapshot
-        # the cross-host jobs per host once per call, not per candidate.
-        cross_by_host = self.ledger.cross_jobs_by_host()
-        degrade = self.ledger.host_degrade if health else None
-        if mode == "learned" and health:
-            mode = "analytic"  # scalar learned path has no degraded view
-        if mode == "learned":
-            idx = [
-                i for i, s in enumerate(subsets)
-                if self._contended_by(cross_by_host, s)
-            ]
-            if idx:
-                # model inference is accounted by the contended predictor's
-                # own predict_seconds; keep this counter wrapper-only
-                before = self.contended.predict_seconds
-                learned = self.contended.predict(
-                    [subsets[i] for i in idx], self.ledger
-                )
-                inner = self.contended.predict_seconds - before
-                for i, p in zip(idx, learned):
-                    if p < out[i]:
-                        out[i] = p
-                        self.stats.n_capped += 1
-        else:
-            for i, s in enumerate(subsets):
-                cap = _cap_from_snapshot(
-                    self.cluster, cross_by_host, s, degrade=degrade
-                )
-                if cap < out[i]:
-                    out[i] = cap
-                    self.stats.n_capped += 1
-        self.stats.wrapper_seconds += time.time() - t0 - inner
-        return out
 
     def _contended_by(
         self, cross_by_host: CrossJobsByHost, subset: Subset

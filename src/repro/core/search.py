@@ -327,7 +327,6 @@ def _pts_search(
             s_curr = list(res.subset)
             # the descent scored every remove-one child of every round
             n_cands += (n0 * (n0 + 1) - k * (k + 1)) // 2
-            telemetry.event("search.pts.fused_scan", steps=n0 - len(s_curr))
             if df is not None:
                 df.note_pts_fused(n0 - len(s_curr))
 
@@ -337,7 +336,6 @@ def _pts_search(
     # matrix with a patched row per child, deduplicated against the
     # prediction cache); the plain batched predict is the fallback.
     fused = hasattr(predictor, "predict_children")
-    rounds = 0
     while len(s_curr) > k:
         children = [s_curr[:i] + s_curr[i + 1:] for i in range(len(s_curr))]
         if fused:
@@ -345,17 +343,12 @@ def _pts_search(
         else:
             preds = predictor.predict(children)
         n_cands += len(children)
-        rounds += 1
         best_i = int(np.argmax(_penalized(preds, children, frag_penalty)))
         if df is not None:  # child i omits s_curr[i]: that GPU bottlenecked
             df.note_pts_round(
                 s_curr[best_i], float(preds[best_i]), len(children)
             )
         s_curr = children[best_i]
-    if rounds:
-        telemetry.event(
-            "search.pts.host_rounds", rounds=rounds, fused_children=fused
-        )
 
     final_bw = float(predictor.predict([s_curr])[0])
     return SearchResult(s_curr, final_bw, time.time() - t0, n_cands + 1)

@@ -34,6 +34,7 @@ import numpy as np
 from jax import lax
 
 from repro.core import features as feat_lib
+from repro.core import telemetry
 from repro.core.bandwidth_sim import BW_SCALE
 from repro.core.cluster import Cluster
 from repro.core.intra_host import IntraHostTables
@@ -601,28 +602,28 @@ class SurrogatePredictor:
                 [parent[:i] + parent[i + 1:] for i in range(n)]
             )
         t0 = time.time()
-        arrays = feat_lib.host_arrays(self.cluster, self.tables)
-        bits, counts = feat_lib.child_bits_counts(arrays, parent)
-        part = counts > 0
-        n_part = part.sum(axis=1)
-        out = np.zeros((n,), np.float64)
-        for i in np.nonzero(n_part == 1)[0]:
-            h = int(np.argmax(part[i]))
-            out[i] = arrays.intra_bw[h, bits[i, h]]  # Stage-1: exact
-        model = np.nonzero(n_part > 1)[0]
+        with telemetry.span("featurize"):
+            arrays = feat_lib.host_arrays(self.cluster, self.tables)
+            bits, counts = feat_lib.child_bits_counts(arrays, parent)
+            part = counts > 0
+            n_part = part.sum(axis=1)
+            out = np.zeros((n,), np.float64)
+            for i in np.nonzero(n_part == 1)[0]:
+                h = int(np.argmax(part[i]))
+                out[i] = arrays.intra_bw[h, bits[i, h]]  # Stage-1: exact
+            model = np.nonzero(n_part > 1)[0]
+            if len(model):
+                ks = np.full((len(model),), n - 1, np.int64)
+                tokens = feat_lib._isolated_channels(
+                    arrays, bits[model], counts[model], ks, self.host_norm
+                )
+                feats, mask = feat_lib._pack_tokens(
+                    tokens, counts[model], self.cluster.n_hosts,
+                    feat_lib.N_FEATURES,
+                )
+        self.stats.featurize_seconds += time.time() - t0
         if len(model):
-            ks = np.full((len(model),), n - 1, np.int64)
-            tokens = feat_lib._isolated_channels(
-                arrays, bits[model], counts[model], ks, self.host_norm
-            )
-            feats, mask = feat_lib._pack_tokens(
-                tokens, counts[model], self.cluster.n_hosts,
-                feat_lib.N_FEATURES,
-            )
-            self.stats.featurize_seconds += time.time() - t0
             out[model] = self._apply_model(feats, mask)
-        else:
-            self.stats.featurize_seconds += time.time() - t0
         self.stats.predict_seconds += time.time() - t0
         return out
 
@@ -669,39 +670,44 @@ class SurrogatePredictor:
         if N0b > SCAN_MAX_SLOTS:
             return None
         t0 = time.time()
-        if caps is None:
-            caps = dt.caps_inf()
-        slot_host = np.zeros((N0b,), np.int32)
-        slot_bit = np.zeros((N0b,), np.int32)
-        slot_host[:n0] = arrays.gpu_host[parent]
-        slot_bit[:n0] = arrays.gpu_bit[parent]
-        sel0 = np.zeros((N0b,), bool)
-        sel0[:n0] = True
-        pbits, pcounts, _, _, _ = feat_lib._batch_bits_counts(
-            arrays, [parent]
-        )
-        bits0 = pbits[0].astype(np.int32)
-        counts0 = pcounts[0].astype(np.int32)
-        H = bits0.shape[0]
-        args = _scan_args(self.params, dt, caps, slot_host, slot_bit,
-                          sel0, bits0, counts0, k, self.host_norm)
-        exe = _compiled_scan((N0b, H, dt.mask_size, caps.shape[0]), args)
-        ys = exe(*args)
-        scores = np.asarray(ys[0])
-        sels = np.asarray(ys[1])
-        elims = np.asarray(ys[2])
-        actives = np.asarray(ys[3])
-        capped = np.asarray(ys[4])
-        R = int(actives.sum())
-        sel = sel0.copy()
-        for r in range(R):
-            sel[elims[r]] = False
-        subset = [parent[i] for i in np.nonzero(sel[:n0])[0]]
-        if R != n0 - k or len(subset) != k:
-            # never expected: counted, so a smoke or bench run can fail on it
-            self.stats.n_scan_declines += 1
-            return None
-        self.stats.scan_seconds += time.time() - t0
+        with telemetry.span("descent") as sp:
+            with telemetry.span("descent.prep"):
+                if caps is None:
+                    caps = dt.caps_inf()
+                slot_host = np.zeros((N0b,), np.int32)
+                slot_bit = np.zeros((N0b,), np.int32)
+                slot_host[:n0] = arrays.gpu_host[parent]
+                slot_bit[:n0] = arrays.gpu_bit[parent]
+                sel0 = np.zeros((N0b,), bool)
+                sel0[:n0] = True
+                pbits, pcounts, _, _, _ = feat_lib._batch_bits_counts(
+                    arrays, [parent]
+                )
+                bits0 = pbits[0].astype(np.int32)
+                counts0 = pcounts[0].astype(np.int32)
+            with telemetry.span("descent.upload"):
+                args = _scan_args(self.params, dt, caps, slot_host, slot_bit,
+                                  sel0, bits0, counts0, k, self.host_norm)
+            with telemetry.span("descent.launch"):
+                exe = _compiled_scan(
+                    (N0b, bits0.shape[0], dt.mask_size, caps.shape[0]), args
+                )
+                ys = exe(*args)
+            with telemetry.span("descent.sync"):
+                scores, sels, elims, actives, capped = (
+                    np.asarray(y) for y in ys
+                )
+            R = int(actives.sum())
+            sel = sel0.copy()
+            for r in range(R):
+                sel[elims[r]] = False
+            subset = [parent[i] for i in np.nonzero(sel[:n0])[0]]
+            if R != n0 - k or len(subset) != k:
+                # never expected: counted, so a smoke or bench run can fail
+                self.stats.n_scan_declines += 1
+                return None
+            sp["steps"] = R
+            self.stats.scan_seconds += time.time() - t0
         self.stats.n_scan_steps += R
         return ScanResult(
             subset=subset,
@@ -753,17 +759,23 @@ class SurrogatePredictor:
     def _predict_model(self, subsets: Sequence[Sequence[int]]) -> np.ndarray:
         if self.naive:
             t0 = time.time()
-            B = len(subsets)
-            Bp = _round_up_pow2(max(B, 1))
-            ids, mask = feat_lib.featurize_gpu_ids(self.cluster, subsets, self.max_k)
-            ids = np.pad(ids, ((0, Bp - B), (0, 0)))
-            mask_p = np.pad(mask, ((0, Bp - B), (0, 0)))
-            mask_p[B:, 0] = 1.0  # keep padded rows non-degenerate
+            with telemetry.span("featurize"):
+                B = len(subsets)
+                Bp = _round_up_pow2(max(B, 1))
+                ids, mask = feat_lib.featurize_gpu_ids(
+                    self.cluster, subsets, self.max_k
+                )
+                ids = np.pad(ids, ((0, Bp - B), (0, 0)))
+                mask_p = np.pad(mask, ((0, Bp - B), (0, 0)))
+                mask_p[B:, 0] = 1.0  # keep padded rows non-degenerate
             self.stats.featurize_seconds += time.time() - t0
             t1 = time.time()
-            preds = self._apply(self.params, jnp.asarray(ids), jnp.asarray(mask_p))
+            with telemetry.span("apply"):
+                preds = self._apply(
+                    self.params, jnp.asarray(ids), jnp.asarray(mask_p)
+                )
+                decoded = np.asarray(preds)[:B]
             self.stats.n_model_calls += B
-            decoded = np.asarray(preds)[:B]
             self.stats.infer_seconds += time.time() - t1
             return decoded
         t0 = time.time()
@@ -771,9 +783,10 @@ class SurrogatePredictor:
             feat_lib.featurize_batch if self.vectorized
             else feat_lib.featurize_batch_loop
         )
-        feats, mask = featurize(
-            self.cluster, self.tables, subsets, host_norm=self.host_norm
-        )
+        with telemetry.span("featurize"):
+            feats, mask = featurize(
+                self.cluster, self.tables, subsets, host_norm=self.host_norm
+            )
         self.stats.featurize_seconds += time.time() - t0
         return self._apply_model(feats, mask)
 
@@ -782,27 +795,29 @@ class SurrogatePredictor:
         paths so the two produce identical floats for identical batches."""
         t1 = time.time()
         B = feats.shape[0]
-        if self.bucket_shapes:
-            used = int(mask.sum(axis=1).max()) if B else 1
-            H = _round_up_pow2(max(used, 1))
-            if H < feats.shape[1]:
-                feats = feats[:, :H]
-                mask = mask[:, :H]
-        batcher = active_batcher()
-        if batcher is not None:
-            # cross-search fusion: the batcher performs the same B padding
-            # (value-neutral), possibly alongside other searches' requests
-            decoded = batcher.apply(self._apply, self.params, feats, mask)
-            self.stats.n_model_calls += B
-            self.stats.infer_seconds += time.time() - t1
-            return decoded
-        Bp = _round_up_pow2(max(B, 1))
-        feats = np.pad(feats, ((0, Bp - B), (0, 0), (0, 0)))
-        mask_p = np.pad(mask, ((0, Bp - B), (0, 0)))
-        mask_p[B:, 0] = 1.0  # keep padded rows non-degenerate
-        preds = self._apply(self.params, jnp.asarray(feats), jnp.asarray(mask_p))
+        with telemetry.span("apply"):
+            if self.bucket_shapes:
+                used = int(mask.sum(axis=1).max()) if B else 1
+                H = _round_up_pow2(max(used, 1))
+                if H < feats.shape[1]:
+                    feats = feats[:, :H]
+                    mask = mask[:, :H]
+            batcher = active_batcher()
+            if batcher is not None:
+                # cross-search fusion: the batcher performs the same B
+                # padding (value-neutral), possibly alongside other
+                # searches' requests
+                decoded = batcher.apply(self._apply, self.params, feats, mask)
+            else:
+                Bp = _round_up_pow2(max(B, 1))
+                feats = np.pad(feats, ((0, Bp - B), (0, 0), (0, 0)))
+                mask_p = np.pad(mask, ((0, Bp - B), (0, 0)))
+                mask_p[B:, 0] = 1.0  # keep padded rows non-degenerate
+                preds = self._apply(
+                    self.params, jnp.asarray(feats), jnp.asarray(mask_p)
+                )
+                decoded = np.asarray(preds)[:B]
         self.stats.n_model_calls += B
-        decoded = np.asarray(preds)[:B]
         self.stats.infer_seconds += time.time() - t1
         return decoded
 
@@ -888,31 +903,35 @@ class ContendedSurrogatePredictor:
                 feat_lib.featurize_contended_batch if self.vectorized
                 else feat_lib.featurize_contended_batch_loop
             )
-            feats, mask = featurize(
-                self.cluster, self.tables, model_pairs,
-                max_tokens=self.max_tokens,
-                include_contenders=self.include_contenders,
-                host_norm=self.host_norm,
-            )
-            if self.bucket_shapes:
-                used = int(mask.sum(axis=1).max())
-                T = _round_up_pow2(max(used, 1))
-                if T < feats.shape[1]:
-                    feats = feats[:, :T]
-                    mask = mask[:, :T]
+            with telemetry.span("featurize"):
+                feats, mask = featurize(
+                    self.cluster, self.tables, model_pairs,
+                    max_tokens=self.max_tokens,
+                    include_contenders=self.include_contenders,
+                    host_norm=self.host_norm,
+                )
+                if self.bucket_shapes:
+                    used = int(mask.sum(axis=1).max())
+                    T = _round_up_pow2(max(used, 1))
+                    if T < feats.shape[1]:
+                        feats = feats[:, :T]
+                        mask = mask[:, :T]
             self.stats.featurize_seconds += time.time() - tf
             ti = time.time()
-            batcher = active_batcher()
-            if batcher is not None:
-                decoded = batcher.apply(self._apply, self.params, feats, mask)
-            else:
-                feats = np.pad(feats, ((0, Bp - B), (0, 0), (0, 0)))
-                mask_p = np.pad(mask, ((0, Bp - B), (0, 0)))
-                mask_p[B:, 0] = 1.0
-                preds = self._apply(
-                    self.params, jnp.asarray(feats), jnp.asarray(mask_p)
-                )
-                decoded = np.asarray(preds)[:B]
+            with telemetry.span("apply"):
+                batcher = active_batcher()
+                if batcher is not None:
+                    decoded = batcher.apply(
+                        self._apply, self.params, feats, mask
+                    )
+                else:
+                    feats = np.pad(feats, ((0, Bp - B), (0, 0), (0, 0)))
+                    mask_p = np.pad(mask, ((0, Bp - B), (0, 0)))
+                    mask_p[B:, 0] = 1.0
+                    preds = self._apply(
+                        self.params, jnp.asarray(feats), jnp.asarray(mask_p)
+                    )
+                    decoded = np.asarray(preds)[:B]
             self.stats.n_model_calls += B
             self.stats.infer_seconds += time.time() - ti
             for i, p in zip(model_idx, decoded):

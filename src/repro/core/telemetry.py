@@ -13,12 +13,20 @@ park/pump events, and defrag background / make-room passes.  Spans land in
 a bounded ring buffer and nest through a *per-thread* stack, so spans from
 racing control-plane workers interleave freely without corrupting either
 structure (hammer-tested in ``tests/test_telemetry.py``).  Tracing is a
-process-wide opt-in (:func:`trace` / :func:`install`): when no tracer is
-installed every instrumented site is a single module-global ``None`` check
-returning a shared no-op span, and the tracer only ever *records* — it
-never touches an rng, a predictor, or a ledger — so placements are
-byte-identical with tracing on or off (regression-pinned across fifo /
-batched x analytic / learned x concurrent workers).
+process-wide opt-in (:func:`trace` / :func:`install`), and the tracer only
+ever *records* — it never touches an rng, a predictor, or a ledger — so
+placements are byte-identical with tracing on or off (regression-pinned
+across fifo / batched x analytic / learned x concurrent workers).
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` of the same name,
+tracer or not, so a JAX profiler session records the program's spans on
+its host plane, on the clock of the device's ``XLA Ops``.  With no
+profiler session the annotation is a C++ no-op; with no tracer installed
+``span()`` returns a falsy object that swallows attribute writes, so
+``if sp:`` guards still skip annotation work.  The profiler sink carries names only; attributes go to the ring.
+Each garbage collection is a ``py.gc`` span on the profiler's sink (a
+``gc.callbacks`` pair installed at import), never in the ring: it belongs
+to no admission.
 
 **Unified metrics registry** (:class:`MetricsRegistry`).  One
 counters/gauges/histograms surface (with labels) that absorbs every stats
@@ -66,6 +74,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import itertools
 import json
 import math
@@ -74,6 +83,8 @@ import time
 import zlib
 from collections import deque
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "AdmissionTracer",
@@ -166,9 +177,10 @@ class Span:
         )
 
 
-class _NullSpan:
-    """Shared no-op span: attribute writes vanish, truthiness is False so
-    call sites can gate optional (more expensive) annotation work."""
+class _ProfilerSpan(TraceAnnotation):
+    """A span with no tracer installed: the profiler's annotation alone.
+    Falsy, and attribute writes vanish, so call sites can gate optional
+    (more expensive) annotation work behind ``if sp:``."""
 
     __slots__ = ()
 
@@ -178,15 +190,6 @@ class _NullSpan:
     def __bool__(self) -> bool:
         return False
 
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
 
 class _OpenSpan:
     """Context manager for one live span: pushes on the caller thread's
@@ -194,17 +197,20 @@ class _OpenSpan:
     at exit.  Exceptions propagate (a crashed admission still records its
     spans, flagged with ``error``)."""
 
-    __slots__ = ("tracer", "span")
+    __slots__ = ("tracer", "span", "annotation")
 
     def __init__(self, tracer: "AdmissionTracer", sp: Span):
         self.tracer = tracer
         self.span = sp
+        self.annotation = TraceAnnotation(sp.name)
 
     def __enter__(self) -> Span:
         _stack().append(self.span)
+        self.annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self.annotation.__exit__(exc_type, exc, tb)
         sp = self.span
         sp.t1 = time.time()
         if exc_type is not None:
@@ -362,11 +368,11 @@ def trace(tracer: AdmissionTracer):
 
 def span(name: str, **attrs):
     """THE instrumentation entry point: a context manager that is a live
-    span under an installed tracer and a shared no-op otherwise.  The
-    disabled cost is one global read per call site."""
+    span under an installed tracer, and otherwise a falsy profiler
+    annotation of ``name`` (a no-op outside a profiler session)."""
     tr = _ACTIVE
     if tr is None:
-        return _NULL_SPAN
+        return _ProfilerSpan(name)
     return tr.span(name, **attrs)
 
 
@@ -375,6 +381,23 @@ def event(name: str, **attrs) -> None:
     tr = _ACTIVE
     if tr is not None:
         tr.event(name, **attrs)
+
+
+_GC_OPEN: List[TraceAnnotation] = []
+
+
+def _gc_span(phase: str, info: Dict) -> None:
+    """``gc.callbacks`` hook: one ``py.gc`` profiler span per collection.
+    Collections never overlap, so one open annotation at a time."""
+    if phase == "start":
+        ann = TraceAnnotation("py.gc", generation=info["generation"])
+        ann.__enter__()
+        _GC_OPEN.append(ann)
+    elif _GC_OPEN:
+        _GC_OPEN.pop().__exit__(None, None, None)
+
+
+gc.callbacks.append(_gc_span)
 
 
 def current_trace_id() -> int:

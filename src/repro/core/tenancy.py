@@ -24,6 +24,7 @@ import dataclasses
 import threading
 from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
+from repro.core import telemetry
 from repro.core.cluster import Cluster
 
 _UID_LOCK = threading.Lock()  # guards the class-level uid counter
@@ -222,7 +223,7 @@ class JobLedger:
         self, job_id: str, gpus: Sequence[int], tenant: str = ""
     ) -> Allocation:
         """Record ``job_id`` as live on ``gpus``.  Returns the allocation."""
-        with self.lock:
+        with telemetry.span("ledger.admit"), self.lock:
             if job_id in self._jobs:
                 raise ValueError(f"job {job_id!r} is already live")
             subset = tuple(sorted(gpus))
@@ -272,7 +273,7 @@ class JobLedger:
 
     def release(self, job_id: str) -> Allocation:
         """Remove a live job, returning its (now freed) allocation."""
-        with self.lock:
+        with telemetry.span("ledger.release"), self.lock:
             alloc = self._jobs.get(job_id)
             if alloc is None:
                 raise KeyError(f"job {job_id!r} is not live")

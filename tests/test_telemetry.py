@@ -14,13 +14,21 @@ Covers the ISSUE 8 acceptance criteria:
     golden ground-truth traces, and triggers the fine-tune hook;
   * **unified stats semantics** — ``to_dict``/``reset``/``merged`` across
     every stats surface, and the control-plane partition invariant
-    asserted at absorb time.
+    asserted at absorb time;
+  * **profiler sink** — under ``jax.profiler.trace`` the program's spans
+    land on the host plane, nested as the admission path nests them, one
+    ``descent`` per fused descent, placements unchanged; with no tracer a
+    span is a falsy annotation that records in no ring.
 """
 
+import gc
+import glob
 import json
 import math
+import os
 import re
 import threading
+from collections import defaultdict
 
 import numpy as np
 import pytest
@@ -83,7 +91,7 @@ def test_span_nesting_parents_and_trace_ids():
 def test_disabled_spans_are_free_and_falsy():
     assert telemetry.active_tracer() is None
     sp = telemetry.span("anything", k=4)
-    assert not sp  # the shared null span is falsy: `if sp:` guards skip
+    assert not sp  # the untraced span is falsy: `if sp:` guards skip
     with sp as inner:
         inner["ignored"] = 1  # swallowed, no error
     telemetry.event("nobody.listening")  # no-op
@@ -168,6 +176,144 @@ def test_tracer_summary_and_jsonl(tmp_path):
     rows = [json.loads(line) for line in path.read_text().splitlines()]
     assert [r["name"] for r in rows] == ["a", "a", "a", "b"]
     assert all("trace_id" in r and "t0" in r for r in rows)
+
+
+def test_untraced_span_is_a_falsy_profiler_annotation():
+    """With no tracer installed a span is the profiler's annotation alone:
+    falsy, deaf to attribute writes, and recorded in no ring."""
+    import jax
+
+    bystander = AdmissionTracer()  # constructed, never installed
+    assert telemetry.active_tracer() is None
+    sp = telemetry.span("descent", k=4)
+    assert isinstance(sp, jax.profiler.TraceAnnotation)
+    assert not sp
+    with sp as inner:
+        assert inner is sp and not inner
+        inner["steps"] = 3
+        with telemetry.span("descent.sync") as child:
+            child["ignored"] = True
+            assert not child
+    assert len(bystander) == 0 and bystander.n_spans == 0
+    assert telemetry.current_trace_id() == -1
+
+
+# ---------------------------------------------------------------------------
+# Profiler sink: the program's spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+# the spans of the fused-descent admission path, below the search
+PROGRAM_SPANS = (
+    "descent", "descent.prep", "descent.upload", "descent.launch",
+    "descent.sync", "cap.table", "cap.degrade", "featurize", "apply",
+    "ledger.admit", "ledger.release", "py.gc",
+)
+
+
+def _host_spans(log_dir):
+    """name -> [(thread line, start ns, end ns)] from a profile's host
+    planes."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(log_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    out = defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                s = int(e.start_ns)
+                out[e.name].append(
+                    ((plane.name, i), s, s + int(e.duration_ns)))
+    return out
+
+
+def _inside(inner, outers):
+    line, s, e = inner
+    return any(ol == line and os_ <= s and e <= oe for ol, os_, oe in outers)
+
+
+def _fused_replay(cl, sim, tables, params, log_dir=None):
+    """A short fifo replay with the fused descent on the device path, under
+    the profiler when ``log_dir`` is given -> (placements, fused descents)."""
+    import jax
+
+    disp = core.BandPilotDispatcher(
+        cl, tables, core.SurrogatePredictor(cl, tables, params))
+    placed, n_fused = [], [0]
+    inner_admit = disp.admit
+    inner_elim = disp.predictor.eliminate_to
+
+    def admit(job_id, k, rng=None, tenant=""):
+        alloc = inner_admit(job_id, k, rng=rng, tenant=tenant)
+        placed.append((job_id, tuple(alloc.gpus)))
+        return alloc
+
+    def eliminate_to(parent, k):
+        res = inner_elim(parent, k)
+        n_fused[0] += res is not None
+        return res
+
+    disp.admit = admit
+    disp.predictor.eliminate_to = eliminate_to
+    trace = core.poisson_trace(
+        cl, 8, np.random.default_rng(5), mean_interarrival=1.0,
+        mean_duration=6.0, k_choices=range(6, 13))
+    sched = core.AdmissionScheduler(cl, sim, tables, disp)
+    if log_dir is None:
+        sched.run(trace)
+    else:
+        with jax.profiler.trace(str(log_dir)):
+            sched.run(trace)
+            gc.collect()
+    return placed, n_fused[0]
+
+
+def test_program_spans_on_profiler_host_plane(h100, tmp_path):
+    """A few fused-descent admissions under the JAX profiler: every
+    program span is on the host plane, the descent's phases nest inside
+    ``descent`` inside ``search.pts`` inside ``dispatcher.dispatch``, one
+    ``descent`` per fused descent, and the placements equal an unprofiled
+    run's byte for byte."""
+    import jax
+
+    cl, sim, tables = h100
+    params = core.surrogate.init_hierarchical_params(jax.random.PRNGKey(0))
+    base, _ = _fused_replay(cl, sim, tables, params)
+    placed, n_fused = _fused_replay(cl, sim, tables, params, tmp_path)
+    assert placed == base and len(placed) == 8
+    spans = _host_spans(tmp_path)
+    missing = [n for n in PROGRAM_SPANS if not spans.get(n)]
+    assert not missing, f"not on the host plane: {missing}"
+    assert n_fused > 0 and len(spans["descent"]) == n_fused
+    for phase in ("descent.prep", "descent.upload", "descent.launch",
+                  "descent.sync"):
+        assert len(spans[phase]) == n_fused
+        assert all(_inside(ev, spans["descent"]) for ev in spans[phase])
+    assert all(_inside(ev, spans["search.pts"]) for ev in spans["descent"])
+    assert all(_inside(ev, spans["dispatcher.dispatch"])
+               for ev in spans["search.pts"])
+    assert len(spans["dispatcher.dispatch"]) == len(placed)
+
+
+def test_traced_descent_span_carries_steps(h100):
+    """Under a tracer the ring holds the same descent spans, with the
+    phases as children and the step count as an attribute."""
+    import jax
+
+    cl, sim, tables = h100
+    params = core.surrogate.init_hierarchical_params(jax.random.PRNGKey(0))
+    pred = core.SurrogatePredictor(cl, tables, params)
+    parent = list(range(4, 20))
+    tr = AdmissionTracer()
+    with telemetry.trace(tr):
+        res = pred.eliminate_to(parent, 8)
+    (d,) = tr.spans("descent")
+    assert d.attrs["steps"] == res.n_rounds == 8
+    phases = [s.name for s in tr.spans() if s.parent_id == d.span_id]
+    assert phases == ["descent.prep", "descent.upload", "descent.launch",
+                      "descent.sync"]
 
 
 # ---------------------------------------------------------------------------
