@@ -32,6 +32,8 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import features as feat_lib
@@ -312,6 +314,7 @@ class ContentionAwarePredictor:
         self._snap_version: Optional[int] = None
         self._snap: Optional[_SnapshotArrays] = None
         self._cap_tab: Optional[np.ndarray] = None
+        self._cap_dev: Optional[jax.Array] = None
         self._cap_tab_version: Optional[Tuple[int, int]] = None
 
     # legacy instrumentation names
@@ -383,7 +386,8 @@ class ContentionAwarePredictor:
         the base predictor's :class:`~repro.core.surrogate.ScanResult` or
         None (caller falls back to the host loop): learned mode under a
         contended ledger, non-vectorized wrappers, cap-incompatible bases,
-        and parents overlapping live jobs all decline."""
+        parents overlapping live jobs and single-host parents all
+        decline."""
         base_elim = getattr(self.base, "eliminate_to", None)
         if base_elim is None:
             return None
@@ -392,6 +396,8 @@ class ContentionAwarePredictor:
             return base_elim(parent, k)  # exact pass-through, like _degrade
         if not self.ledger.busy().isdisjoint(parent):
             return None  # cap depends on disjointness: not table-gatherable
+        if len(self.cluster.partition_by_host(parent)) < 2:
+            return None  # the base declines it: build and upload no table
         mode = "analytic" if self.force_analytic else self.mode
         tables = getattr(self.base, "tables", None)
         caps = None
@@ -417,13 +423,14 @@ class ContentionAwarePredictor:
 
     def _cap_table(
         self, dt: "feat_lib.DeviceTables", snap: _SnapshotArrays
-    ) -> np.ndarray:
+    ) -> jax.Array:
         """The analytic cap tabulated over the per-host count lattice, for
         GPU-disjoint candidates against this ledger version.  The same
         float64 program as :func:`_caps_from_snapshot_batched` with
         ``disjoint == 1`` (so ``c_h = 1 + cross-jobs touching h``),
         evaluated per lattice point and cast to float32 once — a device
-        gather lands on exactly ``np.float32(host-path cap)``."""
+        gather lands on exactly ``np.float32(host-path cap)``.  Returns
+        the table's device copy, uploaded once per ledger version."""
         v = (self.ledger.uid, self.ledger.version)
         if self._cap_tab_version != v or self._cap_tab is None:
             lat = dt.cap_lattice()
@@ -448,8 +455,10 @@ class ContentionAwarePredictor:
                 )
                 caps[idx] = inter * lat.jitter[idx]
             self._cap_tab = caps.astype(np.float32)
+            self._cap_dev = jnp.asarray(self._cap_tab)
+            self.stats.n_descent_uploads += 1
             self._cap_tab_version = v
-        return self._cap_tab
+        return self._cap_dev
 
     def _degrade(
         self, subsets: Sequence[Subset], iso: np.ndarray

@@ -50,8 +50,9 @@ recomputed), skipping the per-GPU accumulation entirely.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bandwidth_sim import BW_SCALE, _jitter
@@ -689,6 +690,19 @@ class _CapLattice:
         self.jitter = jitter    # [L] deterministic fabric jitter factor
 
 
+class ResidentTables(NamedTuple):
+    """Device copies of one cluster's descent tables, uploaded once and
+    passed to every fused descent as they are (no per-descent transfer)."""
+
+    tok0: jnp.ndarray        # [H_all, W] float32
+    tok4: jnp.ndarray        # [H_all, W] float32
+    tok4_zero: jnp.ndarray   # [H_all, W] float32 (host_norm=False)
+    stage1: jnp.ndarray      # [H_all, W] float32
+    strides: jnp.ndarray     # [H_all] int32 lattice strides
+    n_gpus_f: jnp.ndarray    # [] float32
+    caps_inf: jnp.ndarray    # [L] float32, all +inf
+
+
 class DeviceTables:
     """Float32 gather tables for the fused on-device PTS scan.
 
@@ -708,6 +722,7 @@ class DeviceTables:
     function is a ``[L]`` table built in microseconds of numpy
     (:meth:`cap_lattice` holds the ledger-independent geometry and the
     per-point fabric jitter, computed once per cluster).
+    :meth:`resident` holds the tables' device copies, uploaded once.
     """
 
     def __init__(self, cluster: Cluster, tables: IntraHostTables):
@@ -740,6 +755,7 @@ class DeviceTables:
         self.n_gpus_f = np.float32(max(cluster.n_gpus, 1))
         self._lattice: Optional[_CapLattice] = None
         self._caps_inf: Optional[np.ndarray] = None
+        self._resident: Optional[ResidentTables] = None
 
     def cap_lattice(self) -> _CapLattice:
         """Lazy per-cluster lattice geometry + per-point fabric jitter.
@@ -778,6 +794,20 @@ class DeviceTables:
                 (self.lattice_size,), np.inf, np.float32
             )
         return self._caps_inf
+
+    def resident(self) -> ResidentTables:
+        """The tables' device copies, uploaded on the first call."""
+        if self._resident is None:
+            self._resident = ResidentTables(
+                tok0=jnp.asarray(self.tok0),
+                tok4=jnp.asarray(self.tok4),
+                tok4_zero=jnp.asarray(self.tok4_zero),
+                stage1=jnp.asarray(self.stage1),
+                strides=jnp.asarray(self.strides.astype(np.int32)),
+                n_gpus_f=jnp.asarray(self.n_gpus_f),
+                caps_inf=jnp.asarray(self.caps_inf()),
+            )
+        return self._resident
 
 
 def device_tables(cluster: Cluster, tables: IntraHostTables) -> DeviceTables:

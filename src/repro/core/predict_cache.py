@@ -66,6 +66,7 @@ class PredictorStats:
     n_scan_steps: int = 0         # elimination rounds executed on-device
     n_scan_declines: int = 0      # in-envelope descents whose device result
     #                               was inconsistent (the host loop ran)
+    n_descent_uploads: int = 0    # host->device transfers issued by descents
     wrapper_seconds: float = 0.0    # contention-wrap overhead (excl. base)
     n_capped: int = 0             # candidates whose estimate was degraded
     cache_hits: int = 0

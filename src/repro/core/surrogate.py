@@ -378,12 +378,11 @@ def audit_descent(predictor, res: ScanResult, parent: Sequence[int],
     return DescentAudit(max_rel_gap=gap, near_ties=ties, violations=bad)
 
 
-def _pts_scan(params, tok0, tok4, stage1, cap_tab, strides, slot_host,
-              slot_bit, sel0, bits0, counts0, k, n_gpus_f):
-    """The fused descent: traced once per (N0b, H, W, L) shape bucket.
+def _descent_rounds(params, tok0, tok4, stage1, cap_tab, strides, slot_host,
+                    slot_bit, sel0, bits0, counts0, k, n_gpus_f):
+    """The descent's rounds -> per-round (scores, sels, elims, actives,
+    n_capped), each stacked over ``N0b - 1`` rounds.
 
-    All tables and scalars are runtime arguments, so one compiled
-    executable serves every cluster/ledger/k sharing the bucket shapes.
     Fixed trip count ``N0b - 1`` with a ``lax.cond`` gate: rounds after the
     descent reaches ``k`` are no-ops (carry passes through unchanged).
     """
@@ -461,6 +460,57 @@ def _pts_scan(params, tok0, tok4, stage1, cap_tab, strides, slot_host,
     return ys
 
 
+# One descent moves one packed int32 vector each way.  The argument vector
+# is [slot_host | slot_bit | sel0 | bits0 | counts0 | k], of length
+# 3*N0b + 2*H + 1; the result is one [N0b - 1, 2*N0b + 3] row per round:
+# [score bits | sel | elim | active | n_capped].  Scores travel as their
+# float32 bit patterns, so the packing is exact.
+
+def _pack_args(slot_host, slot_bit, sel0, bits0, counts0, k) -> np.ndarray:
+    """The per-descent arguments as the one int32 vector ``_pts_scan``
+    takes."""
+    return np.concatenate(
+        [slot_host, slot_bit, sel0, bits0, counts0, [k]]
+    ).astype(np.int32)
+
+
+def _pts_scan(params, tok0, tok4, stage1, cap_tab, strides, n_gpus_f,
+              packed):
+    """The fused descent: traced once per (N0b, H, W, L) shape bucket.
+
+    All tables and scalars are runtime arguments, so one compiled
+    executable serves every cluster/ledger/k sharing the bucket shapes.
+    The offsets into ``packed`` follow from its length and ``H``.
+    """
+    H = tok0.shape[0]
+    N0b = (packed.shape[0] - 2 * H - 1) // 3
+    o = 3 * N0b
+    scores, sels, elims, actives, capped = _descent_rounds(
+        params, tok0, tok4, stage1, cap_tab, strides,
+        packed[:N0b], packed[N0b:2 * N0b], packed[2 * N0b:o] != 0,
+        packed[o:o + H], packed[o + H:o + 2 * H], packed[-1], n_gpus_f,
+    )
+    return jnp.concatenate([
+        lax.bitcast_convert_type(scores, jnp.int32),
+        sels.astype(jnp.int32),
+        elims[:, None],
+        actives.astype(jnp.int32)[:, None],
+        capped[:, None],
+    ], axis=1)
+
+
+def _unpack_result(out: np.ndarray, n0b: int):
+    """``_pts_scan``'s packed rows -> (scores f32, sels bool, elims i32,
+    actives bool, n_capped i32), each ``[N0b - 1, ...]``."""
+    return (
+        np.ascontiguousarray(out[:, :n0b]).view(np.float32),
+        out[:, n0b:2 * n0b] != 0,
+        out[:, 2 * n0b],
+        out[:, 2 * n0b + 1] != 0,
+        out[:, 2 * n0b + 2],
+    )
+
+
 # (N0b, H_all, 2**max_g, lattice_size) -> AOT-compiled executable.  Tables
 # and scalars are runtime args, so e.g. H100 and Het-4Mix (both 4x8) share
 # every bucket's executable — and so do every ledger state and every k.
@@ -469,26 +519,22 @@ _SCAN_COMPILED: Dict[Tuple[int, int, int, int], Any] = {}
 _pts_scan_jit = jax.jit(_pts_scan)
 
 
-def _scan_args(params, dt, cap_tab, slot_host, slot_bit, sel0, bits0,
-               counts0, k, host_norm):
+def _scan_args(params, dt, cap_tab, packed, host_norm):
     """Build a descent's argument tuple — ONE code path used at both AOT
     lower time and call time, so avals (shape/dtype/weak_type) always match
-    the compiled executable's signature."""
-    tok4 = dt.tok4 if host_norm else dt.tok4_zero
+    the compiled executable's signature.  The cluster's tables are its
+    resident device copies; ``cap_tab`` is uploaded only when it comes as
+    numpy, and ``packed`` (:func:`_pack_args`) always is: one transfer."""
+    res = dt.resident()
     return (
         params,
-        jnp.asarray(dt.tok0),
-        jnp.asarray(tok4),
-        jnp.asarray(dt.stage1),
+        res.tok0,
+        res.tok4 if host_norm else res.tok4_zero,
+        res.stage1,
         jnp.asarray(cap_tab),
-        jnp.asarray(dt.strides.astype(np.int32)),
-        jnp.asarray(slot_host),
-        jnp.asarray(slot_bit),
-        jnp.asarray(sel0),
-        jnp.asarray(bits0),
-        jnp.asarray(counts0),
-        jnp.int32(k),
-        jnp.float32(dt.n_gpus_f),
+        res.strides,
+        res.n_gpus_f,
+        jnp.asarray(packed),
     )
 
 
@@ -651,11 +697,12 @@ class SurrogatePredictor:
         """Run the whole PTS elimination descent ``|parent| -> k`` as one
         fused on-device ``lax.scan`` (see the module section above).
 
-        ``caps`` is a float32 ``[lattice_size]`` analytic-cap table (the
-        contention wrapper builds one per ledger version); None means
-        uncapped (isolated scoring).  Returns a :class:`ScanResult`, or
-        None when the configuration is outside the scan envelope — the
-        caller falls back to the host loop, which is always correct."""
+        ``caps`` is a float32 ``[lattice_size]`` analytic-cap table, numpy
+        or already on the device (the contention wrapper uploads one per
+        ledger version); None means uncapped (isolated scoring).  Returns
+        a :class:`ScanResult`, or None when the configuration is outside
+        the scan envelope — the caller falls back to the host loop, which
+        is always correct."""
         env = self._scan_envelope()
         if env is None:
             return None
@@ -673,34 +720,33 @@ class SurrogatePredictor:
         with telemetry.span("descent") as sp:
             with telemetry.span("descent.prep"):
                 if caps is None:
-                    caps = dt.caps_inf()
-                slot_host = np.zeros((N0b,), np.int32)
-                slot_bit = np.zeros((N0b,), np.int32)
-                slot_host[:n0] = arrays.gpu_host[parent]
-                slot_bit[:n0] = arrays.gpu_bit[parent]
-                sel0 = np.zeros((N0b,), bool)
-                sel0[:n0] = True
+                    caps = dt.resident().caps_inf
+                pad = np.zeros((N0b - n0,), np.int32)
                 pbits, pcounts, _, _, _ = feat_lib._batch_bits_counts(
                     arrays, [parent]
                 )
-                bits0 = pbits[0].astype(np.int32)
-                counts0 = pcounts[0].astype(np.int32)
+                packed = _pack_args(
+                    np.concatenate([arrays.gpu_host[parent], pad]),
+                    np.concatenate([arrays.gpu_bit[parent], pad]),
+                    np.arange(N0b) < n0, pbits[0], pcounts[0], k,
+                )
             with telemetry.span("descent.upload"):
-                args = _scan_args(self.params, dt, caps, slot_host, slot_bit,
-                                  sel0, bits0, counts0, k, self.host_norm)
+                args = _scan_args(self.params, dt, caps, packed,
+                                  self.host_norm)
+                self.stats.n_descent_uploads += 1 + isinstance(
+                    caps, np.ndarray)
             with telemetry.span("descent.launch"):
                 exe = _compiled_scan(
-                    (N0b, bits0.shape[0], dt.mask_size, caps.shape[0]), args
+                    (N0b, pbits.shape[1], dt.mask_size, caps.shape[0]), args
                 )
-                ys = exe(*args)
+                out = exe(*args)
             with telemetry.span("descent.sync"):
-                scores, sels, elims, actives, capped = (
-                    np.asarray(y) for y in ys
+                scores, sels, elims, actives, capped = _unpack_result(
+                    np.asarray(out), N0b
                 )
             R = int(actives.sum())
-            sel = sel0.copy()
-            for r in range(R):
-                sel[elims[r]] = False
+            sel = np.arange(N0b) < n0
+            sel[elims[:R]] = False
             subset = [parent[i] for i in np.nonzero(sel[:n0])[0]]
             if R != n0 - k or len(subset) != k:
                 # never expected: counted, so a smoke or bench run can fail
@@ -740,16 +786,19 @@ class SurrogatePredictor:
                 b *= 2
         spent = 0.0
         H = self.cluster.n_hosts
-        caps = dt.caps_inf()
+        caps = dt.resident().caps_inf
         for N0b in buckets:
             key = (N0b, H, dt.mask_size, caps.shape[0])
             if key in _SCAN_COMPILED:
                 continue
             args = _scan_args(
                 self.params, dt, caps,
-                np.zeros((N0b,), np.int32), np.ones((N0b,), np.int32),
-                np.ones((N0b,), bool), np.zeros((H,), np.int32),
-                np.zeros((H,), np.int32), 1, self.host_norm,
+                _pack_args(
+                    np.zeros((N0b,), np.int32), np.ones((N0b,), np.int32),
+                    np.ones((N0b,), bool), np.zeros((H,), np.int32),
+                    np.zeros((H,), np.int32), 1,
+                ),
+                self.host_norm,
             )
             t0 = time.time()
             _compiled_scan(key, args)

@@ -743,6 +743,7 @@ def absorb_predictor_stats(reg: MetricsRegistry, stats, **labels) -> None:
         ("n_capped", "candidates degraded by a contention branch"),
         ("n_scan_steps", "fused on-device elimination rounds"),
         ("n_scan_declines", "inconsistent fused descents redone on the host"),
+        ("n_descent_uploads", "host-to-device transfers issued by descents"),
         ("cache_hits", "prediction-cache hits"),
         ("cache_misses", "prediction-cache misses"),
     ):

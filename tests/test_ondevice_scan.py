@@ -15,6 +15,8 @@ The load-bearing guarantees:
   within ``SCORE_RTOL`` of a solo apply (property-based, concurrent threads
   included);
 * the LRU-capped lifetime memo can only forget values, never change them;
+* a descent moves one packed vector each way and returns bit for bit what
+  the unpacked layout returns; the cluster's tables stay on the device;
 * ``PredictorStats`` accounts the scan path in its own bucket — no
   double-counting through the ``collect_stats`` chain merge.
 """
@@ -273,6 +275,120 @@ def test_warm_scan_idempotent(stack):
     assert disp.aot_warm_seconds == 0.0  # warmed above: nothing left to do
     cold = core.BandPilotDispatcher(cl, tables, pred, aot_warm=False)
     assert cold.aot_warm_seconds == 0.0
+
+
+# ---------------------------------------------------------------------------
+# Packed descent: one argument vector up, one result array down
+# ---------------------------------------------------------------------------
+
+_rounds_jit = jax.jit(surr._descent_rounds)
+
+
+def _descent_inputs(cl, tables, parent):
+    """A descent's per-slot and per-host argument arrays, as the unpacked
+    layout took them -> (N0b, slot_host, slot_bit, sel0, bits0, counts0)."""
+    arrays = feat.host_arrays(cl, tables)
+    n0 = len(parent)
+    n0b = max(surr._round_up_pow2(n0), surr.SCAN_MIN_SLOTS)
+    slot_host = np.zeros((n0b,), np.int32)
+    slot_bit = np.zeros((n0b,), np.int32)
+    slot_host[:n0] = arrays.gpu_host[parent]
+    slot_bit[:n0] = arrays.gpu_bit[parent]
+    pbits, pcounts, _, _, _ = feat._batch_bits_counts(arrays, [parent])
+    return (n0b, slot_host, slot_bit, np.arange(n0b) < n0,
+            pbits[0].astype(np.int32), pcounts[0].astype(np.int32))
+
+
+def _bytes(a):
+    return np.ascontiguousarray(a).view(np.uint8)
+
+
+_BUCKET_DESCENTS = {8: ((8, 3), (6, 2)), 16: ((12, 5), (16, 9)),
+                    32: ((20, 4), (26, 13))}
+
+
+@pytest.mark.parametrize("capped", [False, True], ids=["uncapped", "capped"])
+@pytest.mark.parametrize("bucket", sorted(_BUCKET_DESCENTS))
+def test_packed_descent_bit_identical(stack, bucket, capped):
+    """The packed descent's unpacked rows, and the ScanResult built from
+    them, equal the unpacked layout's five outputs bit for bit, in every
+    slot bucket, with and without a cap table."""
+    cl, sim, tables, params = stack
+    pred = core.SurrogatePredictor(cl, tables, params)
+    dt = feat.device_tables(cl, tables)
+    led = _tenanted_ledger(cl)
+    caps = handed = dt.caps_inf()      # eliminate_to takes numpy caps ...
+    if capped:
+        wrapped = core.ContentionAwarePredictor(cl, pred, led)
+        handed = wrapped._cap_table(dt, wrapped._snapshot())  # ... or device
+        caps = wrapped._cap_tab
+        assert np.isfinite(caps).any()
+        np.testing.assert_array_equal(np.asarray(handed), caps)
+    rng = np.random.default_rng(30 + bucket)
+    for n0, k in _BUCKET_DESCENTS[bucket]:
+        parent = _multi_host_parent(cl, rng, n0, exclude=led.busy())
+        n0b, *inputs = _descent_inputs(cl, tables, parent)
+        assert n0b == bucket
+        # the unpacked layout: every argument and result an array of its own
+        want = [np.asarray(y) for y in _rounds_jit(
+            params, dt.tok0, dt.tok4, dt.stage1, caps,
+            dt.strides.astype(np.int32), *inputs, np.int32(k),
+            np.float32(dt.n_gpus_f),
+        )]
+        packed = surr._pack_args(*inputs, k)
+        out = np.asarray(surr._pts_scan_jit(
+            *surr._scan_args(params, dt, caps, packed, True)))
+        assert out.dtype == np.int32
+        got = surr._unpack_result(out, n0b)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(_bytes(g), _bytes(w))
+        res = pred.eliminate_to(parent, k, caps=handed)
+        R = n0 - k
+        assert res.n_rounds == R and res.n_capped == int(want[4][:R].sum())
+        assert capped or res.n_capped == 0
+        for name, w in (("scores", want[0]), ("sels", want[1]),
+                        ("elims", want[2])):
+            g = getattr(res, name)
+            assert g.dtype == w.dtype and g.shape == w[:R].shape
+            np.testing.assert_array_equal(_bytes(g), _bytes(w[:R]))
+        sel = np.arange(n0b) < n0
+        sel[want[2][:R]] = False
+        assert res.subset == [parent[i] for i in np.nonzero(sel[:n0])[0]]
+
+
+def test_descent_tables_stay_resident(stack):
+    """The cluster's tables are uploaded once; a descent then uploads its
+    packed arguments alone, plus the cap table when the ledger moved."""
+    cl, sim, tables, params = stack
+    res = feat.device_tables(cl, tables).resident()
+    assert feat.device_tables(cl, tables).resident() is res
+    assert all(a is b for a, b in zip(
+        feat.device_tables(cl, tables).resident(), res))
+    pred = core.SurrogatePredictor(cl, tables, params)
+    led = _tenanted_ledger(cl)
+    wrapped = core.ContentionAwarePredictor(cl, pred, led)
+    later = [cl.hosts[2].gpu_ids[7], cl.hosts[3].gpu_ids[7]]
+    rng = np.random.default_rng(40)
+    parent = _multi_host_parent(cl, rng, 14,
+                                exclude=led.busy() | set(later))
+
+    def uploads(parent, k, declines=False):
+        before = core.collect_stats(wrapped).n_descent_uploads
+        assert (wrapped.eliminate_to(parent, k) is None) == declines
+        return core.collect_stats(wrapped).n_descent_uploads - before
+
+    single_host = list(cl.hosts[2].gpu_ids[:6])  # no descent, no cap table
+    assert uploads(single_host, 3, declines=True) == 0
+    assert uploads(parent, 7) == 2        # new ledger version: cap table
+    assert uploads(parent, 7) == 1        # same version: packed args only
+    assert uploads(parent, 5) == 1
+    led.admit("c", later)                 # a commit moves the version
+    assert uploads(parent, 7) == 2
+    assert uploads(parent, 6) == 1
+    before = pred.stats.n_descent_uploads  # isolated: packed args only
+    assert pred.eliminate_to(parent, 7) is not None
+    assert pred.stats.n_descent_uploads == before + 1
 
 
 # ---------------------------------------------------------------------------

@@ -64,16 +64,17 @@ def test_pts_scan_compiles(one_chip, h100, n0b):
     cl, tables, params = h100
     dt = feat.device_tables(cl, tables)
     H = cl.n_hosts
-    args = surr._scan_args(
-        params, dt, dt.caps_inf(),
+    packed = surr._pack_args(
         np.zeros((n0b,), np.int32), np.ones((n0b,), np.int32),
         np.ones((n0b,), bool), np.zeros((H,), np.int32),
-        np.zeros((H,), np.int32), 1, True,
+        np.zeros((H,), np.int32), 1,
     )
+    assert packed.shape == (3 * n0b + 2 * H + 1,)
+    args = surr._scan_args(params, dt, dt.caps_inf(), packed, True)
     exe = surr._pts_scan_jit.lower(*_on(one_chip, args)).compile()
-    scores, sels, elims, actives, capped = exe.out_info
-    assert scores.shape == (n0b - 1, n0b) and scores.dtype == jnp.float32
-    assert elims.shape == (n0b - 1,)
+    out = exe.out_info  # one packed result: [score bits | sel | elim,
+    #                     active, n_capped] per round
+    assert out.shape == (n0b - 1, 2 * n0b + 3) and out.dtype == jnp.int32
     assert exe.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
