@@ -67,6 +67,8 @@ class PredictorStats:
     n_scan_declines: int = 0      # in-envelope descents whose device result
     #                               was inconsistent (the host loop ran)
     n_descent_uploads: int = 0    # host->device transfers issued by descents
+    n_closing_from_descent: int = 0  # hybrid searches whose PTS closing
+    #                                  score was the descent's, no apply
     wrapper_seconds: float = 0.0    # contention-wrap overhead (excl. base)
     n_capped: int = 0             # candidates whose estimate was degraded
     cache_hits: int = 0
@@ -257,6 +259,16 @@ class CachedPredictor:
         for s in (self.stats, self.cache.stats):
             s.cache_hits += n_hits
             s.cache_misses += n_misses
+
+    def lookup(self, subset: Sequence[int]) -> Optional[float]:
+        """The cached score of ``subset``, or None: computes and stores
+        nothing, and counts only a hit (a miss is counted where it is
+        computed)."""
+        _, _, out, miss = self._lookup([subset])
+        if miss:
+            return None
+        self._account(1, 0)
+        return float(out[0])
 
     def predict(self, subsets: Sequence[Sequence[int]]) -> np.ndarray:
         store, keys, out, miss = self._lookup(subsets)

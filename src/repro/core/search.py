@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import forensics, telemetry
 from repro.core.cluster import Cluster
 from repro.core.intra_host import IntraHostTables
+from repro.core.surrogate import SCORE_RTOL
 from repro.core.tenancy import JobLedger
 
 Subset = List[int]
@@ -48,6 +49,7 @@ class SearchResult:
     predicted_bw: float
     seconds: float
     n_candidates: int
+    closing: str = "apply"  # PTS: "apply", "cache" or "descent" scored it
 
 
 def _available_by_host(
@@ -273,12 +275,51 @@ def pts_search(
     frag_penalty: FragPenalty = None,
 ) -> SearchResult:
     """Algorithm 2.  Top-down iterative elimination of the bottleneck GPU."""
+    return _traced_pts(cluster, tables, predictor, avail, k, frag_penalty)
+
+
+def _traced_pts(
+    cluster: Cluster,
+    tables: IntraHostTables,
+    predictor,
+    avail: Sequence[int],
+    k: int,
+    frag_penalty: FragPenalty,
+    rival: Optional[float] = None,
+) -> SearchResult:
     with telemetry.span("search.pts", k=k, n_avail=len(avail)) as sp:
-        res = _pts_search(cluster, tables, predictor, avail, k, frag_penalty)
+        res = _pts_search(cluster, tables, predictor, avail, k, frag_penalty,
+                          rival)
         if sp:
             sp["n_candidates"] = res.n_candidates
             sp["predicted_bw"] = res.predicted_bw
+            sp["closing"] = res.closing
         return res
+
+
+def _closing_score(predictor, subset: Subset, descent_bw: Optional[float],
+                   rival: Optional[float]) -> Tuple[float, str]:
+    """PTS's closing score of ``subset``, and where it came from.
+
+    The prediction cache answers first.  A fused descent has already
+    scored ``subset`` within ``SCORE_RTOL`` of the standalone apply (the
+    agreement contract in ``core/surrogate.py``), so its score stands
+    wherever it lies more than ``2 * SCORE_RTOL`` from ``rival``, EHA's
+    score: there both scores put PTS on the same side of EHA, and the
+    hybrid decides as the standalone score would.  Everywhere else (no
+    descent, no rival, a near-tie) the standalone apply scores it.
+    """
+    lookup = getattr(predictor, "lookup", None)
+    hit = lookup(subset) if lookup is not None else None
+    if hit is not None:
+        return hit, "cache"
+    if (descent_bw is not None and rival is not None
+            and abs(descent_bw - rival) > 2 * SCORE_RTOL * abs(rival)):
+        stats = getattr(predictor, "stats", None)
+        if stats is not None:
+            stats.n_closing_from_descent += 1
+        return descent_bw, "descent"
+    return float(predictor.predict([subset])[0]), "apply"
 
 
 def _pts_search(
@@ -288,11 +329,13 @@ def _pts_search(
     avail: Sequence[int],
     k: int,
     frag_penalty: FragPenalty = None,
+    rival: Optional[float] = None,
 ) -> SearchResult:
     t0 = time.time()
     by_host = _available_by_host(cluster, avail)
     s_curr: Subset = sorted(avail)
     n_cands = 0
+    descent_bw = None
     df = forensics.draft()  # one global read when capture is off
 
     # Search pruning: node-insertion heuristic for small requests.  With a
@@ -325,6 +368,7 @@ def _pts_search(
         if res is not None:
             n0 = len(s_curr)
             s_curr = list(res.subset)
+            descent_bw = res.bw
             # the descent scored every remove-one child of every round
             n_cands += (n0 * (n0 + 1) - k * (k + 1)) // 2
             if df is not None:
@@ -350,8 +394,9 @@ def _pts_search(
             )
         s_curr = children[best_i]
 
-    final_bw = float(predictor.predict([s_curr])[0])
-    return SearchResult(s_curr, final_bw, time.time() - t0, n_cands + 1)
+    final_bw, closing = _closing_score(predictor, s_curr, descent_bw, rival)
+    return SearchResult(s_curr, final_bw, time.time() - t0, n_cands + 1,
+                        closing)
 
 
 # ---------------------------------------------------------------------------
@@ -388,11 +433,15 @@ def hybrid_search(
         df.note_search_begin(k, len(avail), frag_penalty is not None)
     eha = eha_search(cluster, tables, predictor, avail, k,
                      frag_penalty=frag_penalty)
-    pts = pts_search(cluster, tables, predictor, avail, k,
-                     frag_penalty=frag_penalty)
-    eha_score, pts_score = eha.predicted_bw, pts.predicted_bw
+    eha_score = eha.predicted_bw
     if frag_penalty is not None:
         eha_score *= 1.0 - frag_penalty(eha.subset)
+    # EHA's score bounds where PTS's closing score may come from the fused
+    # descent (a penalised search runs no descent, so raw scores compare)
+    pts = _traced_pts(cluster, tables, predictor, avail, k, frag_penalty,
+                      rival=eha_score)
+    pts_score = pts.predicted_bw
+    if frag_penalty is not None:
         pts_score *= 1.0 - frag_penalty(pts.subset)
     winner = "EHA" if eha_score >= pts_score else "PTS"
     if df is not None:

@@ -318,9 +318,12 @@ class ScanResult:
     marks the slots still live *entering* round ``r`` (slot i = the i-th
     element of the sorted parent), and ``scores[r]`` holds the f32 child
     scores at those slots (padding / eliminated slots carry mirror-parent
-    garbage and are never selected)."""
+    garbage and are never selected).  ``bw`` is the score the last round
+    gave ``subset`` (its chosen child): capped by the same table, in GB/s,
+    and held to ``SCORE_RTOL`` of the standalone apply like every round."""
 
     subset: List[int]          # the surviving k GPUs, ascending
+    bw: float                  # its score: the last round's chosen child
     n_rounds: int              # active elimination rounds (= |S0| - k)
     n_capped: int              # live children whose cap bound (f32 compare)
     scores: np.ndarray         # [R, N0b] float32 per-round child scores
@@ -757,6 +760,7 @@ class SurrogatePredictor:
         self.stats.n_scan_steps += R
         return ScanResult(
             subset=subset,
+            bw=float(scores[R - 1, elims[R - 1]]),
             n_rounds=R,
             n_capped=int(capped[:R].sum()),
             scores=scores[:R],

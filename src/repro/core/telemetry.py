@@ -744,6 +744,8 @@ def absorb_predictor_stats(reg: MetricsRegistry, stats, **labels) -> None:
         ("n_scan_steps", "fused on-device elimination rounds"),
         ("n_scan_declines", "inconsistent fused descents redone on the host"),
         ("n_descent_uploads", "host-to-device transfers issued by descents"),
+        ("n_closing_from_descent",
+         "PTS closing scores taken from the descent, no apply"),
         ("cache_hits", "prediction-cache hits"),
         ("cache_misses", "prediction-cache misses"),
     ):

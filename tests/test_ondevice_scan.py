@@ -189,6 +189,156 @@ def test_hybrid_search_scan_vs_host_contended(stack):
                       results[False].subset, results[False].predicted_bw)
 
 
+# ---------------------------------------------------------------------------
+# PTS's closing score: the descent's own, cache first, apply near a tie
+# ---------------------------------------------------------------------------
+
+class _Spy:
+    """Forwards to a predictor chain and logs what a search asks of it.
+    With ``eha_value`` every batch of more than one subset (EHA's
+    candidates) scores that value instead, which places EHA's score."""
+
+    def __init__(self, inner, eha_value=None):
+        self.base = inner
+        self.stats = PredictorStats()
+        self.eha_value = eha_value
+        self.log = []
+
+    def __getattr__(self, name):
+        return getattr(self.base, name)
+
+    def predict(self, subsets):
+        self.log.append(("predict", [list(s) for s in subsets]))
+        if self.eha_value is not None and len(subsets) > 1:
+            return np.full((len(subsets),), self.eha_value)
+        return self.base.predict(subsets)
+
+    def eliminate_to(self, parent, k):
+        res = self.base.eliminate_to(parent, k)
+        self.log.append(("descent", res))
+        return res
+
+
+def _closing_case(cl, tenanted):
+    """(ledger, avail, k) for a k > 8 hybrid search: EHA scores a batch of
+    several candidates, PTS descends from a multi-host parent."""
+    led = _tenanted_ledger(cl) if tenanted else JobLedger(cl)
+    free = sorted(set(range(cl.n_gpus)) - led.busy())
+    avail = sorted(np.random.default_rng(19).choice(
+        free, size=20, replace=False).tolist())
+    return led, avail, 10
+
+
+def _standalone(cl, tables, params, led, subset):
+    """The closing score the standalone apply gives, on a fresh chain."""
+    fresh = core.cached_contention_predictor(
+        cl, core.SurrogatePredictor(cl, tables, params), led)
+    return float(fresh.predict([subset])[0])
+
+
+@pytest.mark.parametrize("tenanted", [False, True])
+def test_hybrid_closing_score_from_descent(stack, tenanted):
+    """Cold cache, EHA's score far below PTS's: PTS keeps the descent's
+    closing score bit for bit and no model row is applied for it."""
+    cl, sim, tables, params = stack
+    led, avail, k = _closing_case(cl, tenanted)
+    pred = core.SurrogatePredictor(cl, tables, params)
+    spy = _Spy(core.cached_contention_predictor(cl, pred, led),
+               eha_value=1.0)
+    res = search.hybrid_search(cl, tables, spy, avail, k)
+    (scan,) = [r for kind, r in spy.log if kind == "descent"]
+    assert scan is not None and res.pts.subset == scan.subset
+    assert res.pts.closing == "descent"
+    assert res.pts.predicted_bw == scan.bw
+    assert res.winner == "PTS" and res.predicted_bw == scan.bw
+    assert spy.log[-1][0] == "descent"      # nothing scored after it
+    assert pred.stats.n_model_calls == 0    # EHA's batch was the stub's
+    assert spy.stats.n_closing_from_descent == 1
+    np.testing.assert_allclose(
+        scan.bw, _standalone(cl, tables, params, led, scan.subset),
+        rtol=surr.SCORE_RTOL)
+
+
+def test_hybrid_closing_score_cache_first(stack):
+    """With PTS's subset in the prediction cache the cached value is the
+    closing score, and the descent's is not used."""
+    cl, sim, tables, params = stack
+    led, avail, k = _closing_case(cl, True)
+    first = search.hybrid_search(
+        cl, tables,
+        _Spy(core.cached_contention_predictor(
+            cl, core.SurrogatePredictor(cl, tables, params), led),
+            eha_value=1.0),
+        avail, k)
+    chain = core.cached_contention_predictor(
+        cl, core.SurrogatePredictor(cl, tables, params), led)
+    assert chain.lookup(first.pts.subset) is None
+    warm = float(chain.predict([first.pts.subset])[0])
+    hits = chain.stats.cache_hits
+    assert chain.lookup(first.pts.subset) == warm
+    assert chain.stats.cache_hits == hits + 1
+    spy = _Spy(chain, eha_value=1.0)
+    res = search.hybrid_search(cl, tables, spy, avail, k)
+    assert res.pts.subset == first.pts.subset
+    assert res.pts.closing == "cache" and res.pts.predicted_bw == warm
+    assert spy.stats.n_closing_from_descent == 0
+
+
+@pytest.mark.parametrize("side", [-1, 1])
+def test_hybrid_closing_near_tie_rescored(stack, side):
+    """EHA's score within 2 * SCORE_RTOL of the descent's: PTS's subset
+    is scored by the standalone apply, and the winner is the one that
+    score gives against EHA's."""
+    cl, sim, tables, params = stack
+    led, avail, k = _closing_case(cl, True)
+    probe = core.cached_contention_predictor(
+        cl, core.SurrogatePredictor(cl, tables, params), led)
+    scan = probe.eliminate_to(avail, k)
+    assert scan is not None
+    eha_bw = scan.bw * (1 + side * 0.5 * surr.SCORE_RTOL)
+    spy = _Spy(core.cached_contention_predictor(
+        cl, core.SurrogatePredictor(cl, tables, params), led),
+        eha_value=eha_bw)
+    res = search.hybrid_search(cl, tables, spy, avail, k)
+    assert res.pts.subset == scan.subset
+    assert res.pts.closing == "apply"
+    assert spy.log[-1] == ("predict", [scan.subset])
+    pts_bw = _standalone(cl, tables, params, led, scan.subset)
+    assert res.pts.predicted_bw == pts_bw
+    assert res.winner == ("EHA" if eha_bw >= pts_bw else "PTS")
+    assert spy.stats.n_closing_from_descent == 0
+
+
+def test_closing_apply_without_descent_or_rival(stack):
+    """Public ``pts_search`` (no EHA score to compare), the host loop and a
+    fragmentation-penalised search close with the standalone apply, as
+    before: the closing score is a one-row ``predict`` of the subset."""
+    cl, sim, tables, params = stack
+    led, avail, k = _closing_case(cl, False)
+
+    def standalone(subset):
+        return float(core.SurrogatePredictor(cl, tables, params)
+                     .predict([subset])[0])
+
+    spy = _Spy(core.SurrogatePredictor(cl, tables, params))
+    res = search.pts_search(cl, tables, spy, avail, k)
+    assert any(kind == "descent" and r is not None for kind, r in spy.log)
+    assert res.closing == "apply"
+    assert res.predicted_bw == standalone(res.subset)
+    for spy, penalty in (
+        (_Spy(core.SurrogatePredictor(cl, tables, params, use_scan=False)),
+         None),
+        (_Spy(core.SurrogatePredictor(cl, tables, params)),
+         defrag_mod.make_frag_penalty(cl, led, 0.1)),
+    ):
+        hyb = search.hybrid_search(cl, tables, spy, avail, k,
+                                   frag_penalty=penalty)
+        assert all(r is None for kind, r in spy.log if kind == "descent")
+        assert hyb.pts.closing == "apply"
+        assert hyb.pts.predicted_bw == standalone(hyb.pts.subset)
+        assert spy.stats.n_closing_from_descent == 0
+
+
 def _scan_dispatcher(cl, tables, params, use_scan, **kw):
     pred = core.SurrogatePredictor(cl, tables, params, use_scan=use_scan)
     return core.BandPilotDispatcher(cl, tables, pred, aot_warm=use_scan,
